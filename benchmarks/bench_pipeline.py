@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.pipeline import bubble_fraction, pipeline_apply, sequential_apply
+from repro.launch.mesh import make_mesh
 
 
 def main(argv=None) -> list:
@@ -32,7 +33,7 @@ def main(argv=None) -> list:
         stack = {"w": jax.random.normal(kp, (L, D, D)) * 0.3,
                  "b": jnp.zeros((L, D))}
         x = jax.random.normal(jax.random.PRNGKey(1), (B, D))
-        mesh = jax.make_mesh((8,), ("stage",))
+        mesh = make_mesh((8,), ("stage",))
 
         def block_fn(lp, h):
             return jnp.tanh(h @ lp["w"] + lp["b"])

@@ -26,9 +26,9 @@ def natural_compress(x: jax.Array, key: jax.Array) -> jax.Array:
     """Unbiased stochastic rounding to the nearest powers of two."""
     a = jnp.abs(x).astype(jnp.float32)
     zero = a == 0
-    e = jnp.floor(jnp.log2(jnp.where(zero, 1.0, a)))
-    lo = jnp.exp2(e)
-    p = (a - lo) / lo  # in [0, 1): prob of rounding UP to 2^(e+1)
+    m, e = jnp.frexp(a)  # a = m * 2^e, m in [0.5, 1): a in [lo, 2 lo)
+    lo = jnp.ldexp(1.0, e - 1)  # exact, where exp2 of a float is not
+    p = 2.0 * m - 1.0  # in [0, 1): prob of rounding UP to 2 lo
     up = jax.random.uniform(key, x.shape) < p
     mag = jnp.where(up, lo * 2.0, lo)
     out = jnp.sign(x).astype(jnp.float32) * jnp.where(zero, 0.0, mag)
@@ -39,11 +39,10 @@ def nc_pack(x: jax.Array, key: jax.Array) -> jax.Array:
     """Compress to the int8 wire format (sign in bit 7, exponent code)."""
     a = jnp.abs(x).astype(jnp.float32)
     zero = a == 0
-    e = jnp.floor(jnp.log2(jnp.where(zero, 1.0, a)))
-    lo = jnp.exp2(e)
-    p = (a - lo) / lo
+    m, e = jnp.frexp(a)
+    p = 2.0 * m - 1.0
     up = (jax.random.uniform(key, x.shape) < p).astype(jnp.int32)
-    code = jnp.clip(e.astype(jnp.int32) + up + _BIAS, 1, 127)
+    code = jnp.clip(e - 1 + up + _BIAS, 1, 127)
     code = jnp.where(zero, 0, code)
     sign = (x < 0).astype(jnp.int32) << 7
     return (code | sign).astype(jnp.uint8)
@@ -53,7 +52,7 @@ def nc_unpack(b: jax.Array, dtype=jnp.float32) -> jax.Array:
     bi = b.astype(jnp.int32)
     sign = jnp.where((bi & 0x80) != 0, -1.0, 1.0)
     code = bi & 0x7F
-    mag = jnp.where(code == 0, 0.0, jnp.exp2((code - _BIAS).astype(jnp.float32)))
+    mag = jnp.where(code == 0, 0.0, jnp.ldexp(1.0, code - _BIAS))
     return (sign * mag).astype(dtype)
 
 

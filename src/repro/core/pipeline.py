@@ -20,7 +20,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -101,10 +100,10 @@ def pipeline_apply(block_fn: Callable, stacked_params: Any, x: jax.Array,
             axis)
         return outputs.reshape((B,) + x_all.shape[1:])
 
-    fn = shard_map(stage_fn, mesh=mesh,
-                   in_specs=(pspec_params, P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh,
+                       in_specs=(pspec_params, P()),
+                       out_specs=P(),
+                       check_vma=False)
     return fn(staged, x)
 
 

@@ -202,11 +202,7 @@ def shard(x, *names: Optional[str]):
     present = _mesh_axis_names()
     if not present:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, resolve_spec(x.shape, names))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, resolve_spec(x.shape, names))
 
 
 def mesh_shards(name: str, mesh: Mesh) -> int:

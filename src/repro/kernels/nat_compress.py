@@ -28,11 +28,11 @@ def _pack_kernel(x_ref, u_ref, o_ref):
     u = u_ref[...].astype(jnp.float32)
     a = jnp.abs(x)
     zero = a == 0
-    e = jnp.floor(jnp.log2(jnp.where(zero, 1.0, a)))
-    lo = jnp.exp2(e)
-    p = (a - lo) / lo  # normalized mantissa remainder in [0,1)
+    # a = m * 2^e with m in [0.5, 1): a lies in [2^(e-1), 2^e)
+    m, e = jnp.frexp(a)
+    p = 2.0 * m - 1.0  # normalized mantissa remainder in [0,1), exact
     up = (u < p).astype(jnp.int32)
-    code = jnp.clip(e.astype(jnp.int32) + up + _BIAS, 1, 127)
+    code = jnp.clip(e - 1 + up + _BIAS, 1, 127)
     code = jnp.where(zero, 0, code)
     sign = jnp.where(x < 0, 128, 0)
     o_ref[...] = (code | sign).astype(jnp.int32)
@@ -42,8 +42,9 @@ def _unpack_kernel(b_ref, o_ref):
     bi = b_ref[...]
     sign = jnp.where((bi & 0x80) != 0, -1.0, 1.0)
     code = bi & 0x7F
-    mag = jnp.where(code == 0, 0.0,
-                    jnp.exp2((code - _BIAS).astype(jnp.float32)))
+    # a vector mantissa: Mosaic bitcasts vectors only, not a scalar 1.0
+    one = jnp.ones(code.shape, jnp.float32)
+    mag = jnp.where(code == 0, 0.0, jnp.ldexp(one, code - _BIAS))
     o_ref[...] = (sign * mag).astype(o_ref.dtype)
 
 

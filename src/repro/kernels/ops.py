@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode; on TPU they
-compile to Mosaic.  `use_pallas()` picks per-backend; model code calls
-these wrappers, never pallas_call directly.
+On the CPU backend the kernels run in interpret mode; on every other
+backend they compile to Mosaic.  Model code calls these wrappers, never
+pallas_call directly.
 """
 from __future__ import annotations
 
@@ -20,7 +20,7 @@ from repro.kernels import ref as _ref
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

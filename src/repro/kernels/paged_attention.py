@@ -11,6 +11,11 @@ The page id is data: `PrefetchScalarGridSpec` prefetches the block table
 (and the per-row positions) into SMEM so the k/v BlockSpec index_maps can
 address HBM by `bt[b, j]` before the body runs.
 
+The pool is viewed as (Np, P, Hk*dh) (a free reshape), so one kv head of
+one page is a (P, dh) tile whose last two dims are lane/sublane aligned;
+a (P, 1, dh) block of the 4-D pool would put a 1 against Hk on the
+second-minor dim, which Mosaic does not tile.
+
 Grid: (B, Hk, n_pages_per_row), pages innermost (sequential); the online
 softmax accumulator lives in VMEM scratch across the page dimension,
 exactly like flash_attention.py's k-block loop.
@@ -48,8 +53,8 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j * P <= pos)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32)        # (G, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (P, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)           # (P, dh)
+        v = v_ref[0].astype(jnp.float32)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -90,6 +95,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
     sc = scale if scale is not None else dh ** -0.5
 
     qg = q.reshape(B, Hk, G, dh)
+    kf = k_pool.reshape(Np, P, Hk * dh)
+    vf = v_pool.reshape(Np, P, Hk * dh)
     grid = (B, Hk, n_max)
 
     out = pl.pallas_call(
@@ -100,10 +107,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
             in_specs=[
                 pl.BlockSpec((1, 1, G, dh),
                              lambda b, h, j, bt, pos: (b, h, 0, 0)),
-                pl.BlockSpec((1, P, 1, dh),
-                             lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
-                pl.BlockSpec((1, P, 1, dh),
-                             lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
+                pl.BlockSpec((1, P, dh),
+                             lambda b, h, j, bt, pos: (bt[b, j], 0, h)),
+                pl.BlockSpec((1, P, dh),
+                             lambda b, h, j, bt, pos: (bt[b, j], 0, h)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, dh),
                                    lambda b, h, j, bt, pos: (b, h, 0, 0)),
@@ -116,5 +123,5 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, *,
         out_shape=jax.ShapeDtypeStruct((B, Hk, G, dh), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(pos, jnp.int32),
-      qg, k_pool, v_pool)
+      qg, kf, vf)
     return out.reshape(B, Hq, dh)

@@ -87,11 +87,10 @@ def nc_pack_ref(x, u) -> jax.Array:
     value = sign * 2^(code - 70), code 0 => zero."""
     a = jnp.abs(x).astype(jnp.float32)
     zero = a == 0
-    e = jnp.floor(jnp.log2(jnp.where(zero, 1.0, a)))
-    lo = jnp.exp2(e)
-    p = (a - lo) / lo
+    m, e = jnp.frexp(a)  # a = m * 2^e, m in [0.5, 1)
+    p = 2.0 * m - 1.0
     up = (u < p).astype(jnp.int32)
-    code = jnp.clip(e.astype(jnp.int32) + up + _BIAS, 1, 127)
+    code = jnp.clip(e - 1 + up + _BIAS, 1, 127)
     code = jnp.where(zero, 0, code)
     sign = (x < 0).astype(jnp.int32) << 7
     return (code | sign).astype(jnp.uint8)
@@ -101,8 +100,7 @@ def nc_unpack_ref(bcode, dtype=jnp.float32) -> jax.Array:
     bi = bcode.astype(jnp.int32)
     sign = jnp.where((bi & 0x80) != 0, -1.0, 1.0)
     code = bi & 0x7F
-    mag = jnp.where(code == 0, 0.0,
-                    jnp.exp2((code - _BIAS).astype(jnp.float32)))
+    mag = jnp.where(code == 0, 0.0, jnp.ldexp(1.0, code - _BIAS))
     return (sign * mag).astype(dtype)
 
 

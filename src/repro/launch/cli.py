@@ -13,6 +13,7 @@ spelling, default, and semantics cannot drift between entry points:
 * `load_failure_trace(args)`   — ``--failure-trace`` JSON -> FailureTrace
 * `make_transport(args, trace)`— flags -> SimTransport / ProcTransport
 * `run_traced(args, fn)`       — run under a Recorder, write trace.json
+* `use_compile_cache()`        — JAX's persistent compilation cache
 
 All repro imports are lazy: parsing ``--help`` must not pay the jax
 startup tax.
@@ -20,7 +21,25 @@ startup tax.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 from typing import Any, Callable, Optional
+
+# fixed, never per run, so a later run finds what an earlier one compiled
+CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    cache there and nothing is set here; otherwise the cache is the
+    checkout's ``.jax_cache``.  Entry points call this first; importing a
+    module never does."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
 
 
 def add_cluster_args(ap: argparse.ArgumentParser, *,

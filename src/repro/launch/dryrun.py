@@ -173,4 +173,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import cli
+    cli.use_compile_cache()
     main()

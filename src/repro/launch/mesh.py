@@ -1,4 +1,4 @@
-"""Production meshes (TPU v5e target).
+"""Device meshes: every mesh in the repo is made by `make_mesh`.
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state.  Single pod: 256 chips as (16, 16) ("data", "model"); multi-pod:
@@ -8,21 +8,28 @@ axis crosses DCN, so the launcher maps only low-volume collectives
 """
 from __future__ import annotations
 
-import jax
+from typing import Sequence
 
-# TPU v5e hardware constants (per chip) — used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
+    """A mesh over the first prod(shape) devices with every axis `Auto`:
+    GSPMD propagates shardings from the `with_sharding_constraint`s and
+    jit in/out shardings the model code sets (`repro.core.sharding`)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small CPU mesh for integration tests (requires
-    --xla_force_host_platform_device_count >= data*model)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """(data, model) mesh over the first data*model devices: chips on a
+    TPU host, or CPU devices under
+    --xla_force_host_platform_device_count >= data*model."""
+    return make_mesh((data, model), ("data", "model"))

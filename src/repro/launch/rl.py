@@ -91,4 +91,5 @@ def _rl(args) -> dict:
 if __name__ == "__main__":
     from repro.obs import log as _log
     _log.configure()  # CLI runs show [info] progress; library use stays quiet
+    cli.use_compile_cache()
     rl()

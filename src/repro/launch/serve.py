@@ -36,7 +36,7 @@ from repro.configs import get_config
 from repro.core import sharding as SH
 from repro.launch import cli
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import sharded_argmax
+from repro.launch.steps import named_tree, sharded_argmax
 from repro.models import model as MD
 from repro.obs import recorder as obs
 
@@ -115,7 +115,7 @@ def _serve_continuous(params, cfg, args):
     # drawn lengths never exceed the CLI bounds: cache_len = S + G must
     # hold the longest prompt plus the largest generation budget
     S, G = args.prompt_len, args.gen
-    reqs = _make_stream(cfg, args)
+    reqs = make_stream(cfg, args)
     n_prefix = cfg.num_patches if cfg.arch_type == "vlm" else 0
     cache_len = S + G + n_prefix
     paged = dict(page_size=args.page_size,
@@ -166,7 +166,7 @@ def _serve_continuous(params, cfg, args):
     return {"finished": finished, "stats": st, "t_total": dt}
 
 
-def _make_stream(cfg, args):
+def make_stream(cfg, args):
     """Deterministic mixed-length request stream shared by the continuous
     and fleet paths."""
     from repro.serving import Request
@@ -201,7 +201,7 @@ def _serve_fleet(params, cfg, args):
                        page_size=args.page_size if args.paged else None,
                        num_pages=args.num_pages if args.paged else None,
                        hedged_decode=args.hedged)
-    reqs = _make_stream(cfg, args)
+    reqs = make_stream(cfg, args)
     t0 = time.time()
     try:
         finished = fleet.run(reqs)
@@ -279,16 +279,23 @@ def _serve(args) -> dict:
 
     mesh = make_host_mesh(args.data, args.model)
     with SH.use_mesh(mesh), SH.axis_env(SH.DP_TP_ENV):
-        params = jax.jit(lambda k: MD.init_model(cfg, k))(
-            jax.random.PRNGKey(args.seed))
+        # weights land sharded over the mesh as they are made, never
+        # whole on one device
+        params = jax.jit(
+            lambda k: MD.init_model(cfg, k),
+            out_shardings=named_tree(mesh, MD.model_pspecs(cfg)),
+        )(jax.random.PRNGKey(args.seed))
         if args.replicas:
-            return _serve_fleet(params, cfg, args)
-        if args.continuous:
-            return _serve_continuous(params, cfg, args)
-        return _serve_static(params, cfg, args)
+            out = _serve_fleet(params, cfg, args)
+        elif args.continuous:
+            out = _serve_continuous(params, cfg, args)
+        else:
+            out = _serve_static(params, cfg, args)
+    return {**out, "params": params}
 
 
 if __name__ == "__main__":
     from repro.obs import log as _log
     _log.configure()  # CLI runs show [info] progress; library use stays quiet
+    cli.use_compile_cache()
     serve()

@@ -204,7 +204,8 @@ class StepPlan:
     donate_argnums: Tuple[int, ...] = ()
 
 
-def _ns(mesh, tree):
+def named_tree(mesh, tree):
+    """A pytree of PartitionSpecs -> the same tree of NamedShardings."""
     return jax.tree_util.tree_map(
         lambda p: NamedSharding(mesh, p), tree,
         is_leaf=lambda x: isinstance(x, P))
@@ -227,15 +228,16 @@ def build_plan(cfg: ModelConfig, shape: InputShape, mesh,
         batch_abs = batch_abstract(cfg, B, S, train=True)
         bspecs = batch_pspecs(cfg, batch_abs)
         scalar = P()
-        out_shardings = (_ns(mesh, pspecs), _ns(mesh, opt_specs),
+        out_shardings = (named_tree(mesh, pspecs), named_tree(mesh, opt_specs),
                          {"loss": NamedSharding(mesh, scalar),
                           "gnorm": NamedSharding(mesh, scalar)})
         return StepPlan(
             name=f"train[{cfg.name}x{shape.name}]",
             fn=make_train_step(cfg, opt),
             args=(params_abs, opt_state_abs, batch_abs),
-            in_shardings=(_ns(mesh, pspecs), _ns(mesh, opt_specs),
-                          _ns(mesh, bspecs)),
+            in_shardings=(named_tree(mesh, pspecs),
+                          named_tree(mesh, opt_specs),
+                          named_tree(mesh, bspecs)),
             out_shardings=out_shardings,
             donate_argnums=(0, 1),
         )
@@ -253,9 +255,9 @@ def build_plan(cfg: ModelConfig, shape: InputShape, mesh,
             name=f"prefill[{cfg.name}x{shape.name}]",
             fn=make_prefill_step(cfg, S_cache),
             args=(params_abs, batch_abs),
-            in_shardings=(_ns(mesh, pspecs), _ns(mesh, bspecs)),
+            in_shardings=(named_tree(mesh, pspecs), named_tree(mesh, bspecs)),
             out_shardings=(NamedSharding(mesh, logit_spec),
-                           _ns(mesh, cspecs)),
+                           named_tree(mesh, cspecs)),
         )
 
     if shape.kind == "decode":
@@ -268,10 +270,11 @@ def build_plan(cfg: ModelConfig, shape: InputShape, mesh,
             name=f"decode[{cfg.name}x{shape.name}]",
             fn=make_serve_step(cfg),
             args=(params_abs, cache_abs, tok_abs, pos_abs),
-            in_shardings=(_ns(mesh, pspecs), _ns(mesh, cspecs),
+            in_shardings=(named_tree(mesh, pspecs), named_tree(mesh, cspecs),
                           NamedSharding(mesh, tok_spec),
                           NamedSharding(mesh, P())),
-            out_shardings=(NamedSharding(mesh, tok_spec), _ns(mesh, cspecs)),
+            out_shardings=(NamedSharding(mesh, tok_spec),
+                           named_tree(mesh, cspecs)),
             donate_argnums=(1,),
         )
 
@@ -289,11 +292,12 @@ def build_plan(cfg: ModelConfig, shape: InputShape, mesh,
             name=f"decode_cb[{cfg.name}x{shape.name}]",
             fn=make_serve_cb_step(cfg),
             args=(params_abs, cache_abs, tok_abs, pos_abs, act_abs),
-            in_shardings=(_ns(mesh, pspecs), _ns(mesh, cspecs),
+            in_shardings=(named_tree(mesh, pspecs), named_tree(mesh, cspecs),
                           NamedSharding(mesh, tok_spec),
                           NamedSharding(mesh, row_spec),
                           NamedSharding(mesh, row_spec)),
-            out_shardings=(NamedSharding(mesh, tok_spec), _ns(mesh, cspecs)),
+            out_shardings=(NamedSharding(mesh, tok_spec),
+                           named_tree(mesh, cspecs)),
             donate_argnums=(1,),
         )
 

@@ -24,7 +24,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import (AsyncCheckpointer, latest_step,
                               restore_checkpoint, save_checkpoint)
@@ -33,7 +32,8 @@ from repro.core import sharding as SH
 from repro.data import make_pipeline
 from repro.launch import cli
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import batch_pspecs, batch_abstract, make_train_step
+from repro.launch.steps import (batch_abstract, batch_pspecs,
+                                make_train_step, named_tree)
 from repro.models import model as MD
 from repro.obs import recorder as obs
 from repro.optim.optimizers import get_optimizer, warmup_cosine
@@ -120,11 +120,13 @@ def _train(args) -> dict:
         pspecs = MD.model_pspecs(cfg)
         params = jax.jit(
             lambda k: MD.init_model(cfg, k),
-            out_shardings=jax.tree_util.tree_map(
-                lambda p: NamedSharding(mesh, p), pspecs,
-                is_leaf=lambda x: isinstance(x, P)),
+            out_shardings=named_tree(mesh, pspecs),
         )(jax.random.PRNGKey(args.seed))
-        opt_state = jax.jit(opt.init)(params)
+        # the moments depend on no value of params, so without
+        # out_shardings they would land whole on the default device
+        opt_state = jax.jit(
+            opt.init, out_shardings=named_tree(mesh, opt.state_specs(pspecs)),
+        )(params)
 
         step0 = 0
         if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
@@ -137,9 +139,7 @@ def _train(args) -> dict:
 
         batch_abs = batch_abstract(cfg, args.batch, args.seq)
         bspecs = batch_pspecs(cfg, batch_abs)
-        bshard = jax.tree_util.tree_map(
-            lambda p: NamedSharding(mesh, p), bspecs,
-            is_leaf=lambda x: isinstance(x, P))
+        bshard = named_tree(mesh, bspecs)
         step_fn = jax.jit(
             make_train_step(cfg, opt, compress_grads=args.compress_grads),
             donate_argnums=(0, 1))
@@ -218,4 +218,5 @@ def _train(args) -> dict:
 if __name__ == "__main__":
     from repro.obs import log as _log
     _log.configure()  # CLI runs show [info] progress; library use stays quiet
+    cli.use_compile_cache()
     train()

@@ -18,30 +18,15 @@ assert "--xla_force_host_platform_device_count=8" in \
     os.environ.get("XLA_FLAGS", ""), "worker must run with 8 host devices"
 
 import jax
-
-# The mesh-equivalence divergence seen on some CPU hosts (ROADMAP
-# pre-existing) is NOT kernel reduction order: with jax<0.5's default
-# non-partitionable threefry, `init_model` jitted with out_shardings can
-# return different random bits on some mesh shapes — observed here as the
-# embed table diverging completely (max|diff| ~ 0.1, 100% of elements) on
-# a (4,2) mesh under P('model', None) while (8,1)/(1,8) matched, so no
-# tolerance is defensible.  Partitionable threefry is sharding-invariant
-# by construction (and the default from jax 0.5 on), which makes init
-# bit-identical across meshes; the remaining train-step comparisons below
-# then genuinely measure collective reassociation, at the documented
-# tolerances.  Scoped to this worker: flipping the flag changes every
-# jax.random stream, and the seeded RL/technique tests pin behavior under
-# the session default.
-jax.config.update("jax_threefry_partitionable", True)
-
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import sharding as SH
 from repro.core.pipeline import pipeline_apply, sequential_apply
+from repro.launch.mesh import make_mesh
 from repro.models import model as MD
 from repro.models.config import ModelConfig
 from repro.optim.optimizers import get_optimizer
@@ -80,7 +65,7 @@ P0, P1, LOSS0, G0 = single_device_step()
 
 
 def check(name, env, mesh_shape, axis_names):
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     opt = get_optimizer("adamw", lambda s: 1e-2)
     with SH.use_mesh(mesh), SH.axis_env(env):
         pspecs = MD.model_pspecs(CFG)
@@ -141,7 +126,7 @@ kp = jax.random.PRNGKey(3)
 stack = {"w": jax.random.normal(kp, (L, D, D)) * 0.3,
          "b": jnp.zeros((L, D))}
 x = jax.random.normal(jax.random.PRNGKey(4), (16, D))
-pmesh = jax.make_mesh((8,), ("stage",))
+pmesh = make_mesh((8,), ("stage",))
 
 y_seq = sequential_apply(block_fn, stack, x)
 y_pp = pipeline_apply(block_fn, stack, x, pmesh, num_microbatches=4)
@@ -161,9 +146,7 @@ print("OK pp", flush=True)
 # ---------------------------------------------------------------------------
 # shard_map data-parallel: explicit psum == vmap-mean semantics
 # ---------------------------------------------------------------------------
-from jax.experimental.shard_map import shard_map
-
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_mesh((8,), ("data",))
 W = 8
 xw = jax.random.normal(jax.random.PRNGKey(5), (W, 4, D))
 w0 = jax.random.normal(jax.random.PRNGKey(6), (D,)) * 0.1
@@ -175,22 +158,18 @@ def loss_fn(w, xb, yb):
 
 
 def smap_step(w, xw, yw):
-    # w enters SHARDED (each worker holds its own broadcast row) rather
-    # than replicated: grad w.r.t. a replicated input inside shard_map is
-    # version-dependent (jax<0.5 check_rep rejects the un-psummed
-    # cotangent; newer jax's transpose rule psums it automatically, which
-    # would double-count an explicit one).  With a per-worker row the
-    # gradient is unambiguously local on every version, and the survey's
-    # Fig. 2 all-reduce is the explicit psum below (/W -> worker mean).
+    # w enters sharded, one broadcast row per worker, so its gradient is
+    # local; the survey's Fig. 2 all-reduce is the explicit psum below
+    # (/W -> worker mean).
     wb = jnp.broadcast_to(w[None], (W,) + w.shape)
 
     def worker(wb, xb, yb):
         g = jax.grad(loss_fn)(wb[0], xb[0], yb[0])
         return jax.lax.psum(g, "data") / W
 
-    return shard_map(worker, mesh=mesh8,
-                     in_specs=(P("data"), P("data"), P("data")),
-                     out_specs=P())(wb, xw, yw)
+    return jax.shard_map(worker, mesh=mesh8,
+                         in_specs=(P("data"), P("data"), P("data")),
+                         out_specs=P())(wb, xw, yw)
 
 
 g_sm = smap_step(w0, xw, yw)

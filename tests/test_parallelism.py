@@ -5,15 +5,9 @@ subprocess with XLA_FLAGS set (tests/_par_worker.py); this file asserts on
 its output and adds single-process property tests (bubble fraction,
 sharding-rule resolution).
 
-On the "mesh-equivalence numerics diverge on some CPU hosts" audit
-(ROADMAP pre-existing): the divergence was traced to sharding-DEPENDENT
-random init under jax<0.5's non-partitionable threefry, not to kernel
-reduction order — whole init leaves differed, so no tolerance was
-defensible.  The worker now enables `jax_threefry_partitionable`
-(sharding-invariant bits, the jax>=0.5 default) for bit-identical init
-across meshes, and keeps the original tolerances for the train-step
-comparisons, which measure only collective reassociation.  Details in
-tests/_par_worker.py."""
+The worker checks that sharded init equals single-device init, and the
+train-step results at tolerances that admit only collective
+reassociation."""
 import os
 import pathlib
 import subprocess
@@ -61,10 +55,10 @@ def test_bubble_fraction_formula():
 # sharding-rule resolution (no mesh needed)
 # ---------------------------------------------------------------------------
 def test_resolve_spec_drops_indivisible_dims():
-    import jax
     from jax.sharding import PartitionSpec as P
     from repro.core import sharding as SH
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
     with SH.use_mesh(mesh), SH.axis_env(SH.DP_TP_ENV):
         # 51865 (whisper vocab) is not divisible by any model axis > 1:
         # with a size-1 axis it shards trivially; the API must not raise
@@ -73,9 +67,9 @@ def test_resolve_spec_drops_indivisible_dims():
 
 
 def test_axis_env_filters_absent_mesh_axes():
-    import jax
     from repro.core import sharding as SH
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(1, 1)
     with SH.use_mesh(mesh), SH.axis_env(SH.DP_TP_ENV):
         # 'pod' is not in this mesh; logical batch = ("pod","data") -> data
         spec = SH.logical("batch")
