@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.spans import span
+
 
 class SyntheticBigramSource:
     """next ~ Cat(T[prev]) with a sparse random transition table."""
@@ -97,7 +99,8 @@ class DataPipeline:
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         while True:
-            toks = self.source.sample(self.rng, self.batch, self.seq)
+            with span("data.draw", cat="data"):
+                toks = self.source.sample(self.rng, self.batch, self.seq)
             yield {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
     def batches(self, n: int):

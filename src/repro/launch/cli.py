@@ -4,7 +4,8 @@ Every launcher (train, serve, rl) grows the same cluster surface — which
 transport backs the control plane (``--transport``), where injected
 failures come from (``--failure-trace``), where dying workers flush
 their flight rings (``--flight-dir``) — plus the same "record the run
-and write a Perfetto trace" wrapper (``--trace-out``).  They live here
+and write a Perfetto trace" wrapper (``--trace-out``) and JAX profiler
+capture (``--profile-dir``).  They live here
 once, as argparse argument groups and small factories, so a flag's
 spelling, default, and semantics cannot drift between entry points:
 
@@ -12,7 +13,8 @@ spelling, default, and semantics cannot drift between entry points:
 * `add_trace_args(ap)`         — the observability flag group
 * `load_failure_trace(args)`   — ``--failure-trace`` JSON -> FailureTrace
 * `make_transport(args, trace)`— flags -> SimTransport / ProcTransport
-* `run_traced(args, fn)`       — run under a Recorder, write trace.json
+* `run_traced(args, fn)`       — run under a Recorder and/or the JAX
+  profiler, write trace.json / the profile
 * `use_compile_cache()`        — JAX's persistent compilation cache
 
 All repro imports are lazy: parsing ``--help`` must not pay the jax
@@ -84,6 +86,11 @@ def add_trace_args(ap: argparse.ArgumentParser):
                    help="record the run and write a Chrome/Perfetto "
                         "trace.json here (open in ui.perfetto.dev); "
                         "see repro.obs")
+    g.add_argument("--profile-dir", default=None,
+                   help="capture a JAX profiler trace of the run here "
+                        "(TensorBoard / xprof): the program's spans "
+                        "(repro.obs.spans) and the device's ops on one "
+                        "clock")
     return g
 
 
@@ -111,17 +118,40 @@ def make_transport(args, trace=None):
 
 
 def run_traced(args, fn: Callable[[], Any]) -> Any:
-    """Run ``fn()`` and, when ``--trace-out`` was given, record it and
-    write the Chrome/Perfetto trace on the way out (even on error —
-    a trace of a failed run is the one you want most)."""
+    """Run ``fn()``; with ``--profile-dir`` under the JAX profiler, and
+    with ``--trace-out`` under a Recorder that also notes every backend
+    compile as a ``jax.compile`` instant with its duration, writing the
+    Chrome/Perfetto trace on the way out (even on error — a trace of a
+    failed run is the one you want most)."""
+    profile_dir = getattr(args, "profile_dir", None)
+    if profile_dir:
+        import jax
+        jax.profiler.start_trace(profile_dir)
+        try:
+            return _run_recorded(args, fn)
+        finally:
+            jax.profiler.stop_trace()
+            print(f"wrote profile: {profile_dir}", flush=True)
+    return _run_recorded(args, fn)
+
+
+def _run_recorded(args, fn: Callable[[], Any]) -> Any:
     if not getattr(args, "trace_out", None):
         return fn()
+    import jax
     from repro.obs import recorder as obs
     from repro.obs.trace import write_trace
+
+    def on_duration(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            rec.event("jax.compile", cat="jax", secs=secs)
+
     with obs.recording(obs.Recorder()) as rec:
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
         try:
             return fn()
         finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
             write_trace(args.trace_out, rec.events)
             print(f"wrote trace: {args.trace_out} "
                   f"({len(rec.events)} events)", flush=True)

@@ -150,6 +150,12 @@ def _serve_continuous(params, cfg, args):
           f"occupancy={st['occupancy']:.2f}  "
           f"ticks={st['ticks']} (prefill {st['prefill_ticks']}, "
           f"decode {st['decode_ticks']})")
+    # the host's side of the engine: chunk dispatches, every harvested
+    # token, and the host seconds of each `serve.<part>` span
+    print(f"host: chunks={st['decode_chunks']} "
+          f"emitted={st['emitted_tokens']} " + " ".join(
+              f"{p}={st[f'host_{p}_s']:.3f}s" for p in
+              ("coverage", "dispatch", "wait", "harvest", "prefill")))
     if args.paged:
         print(f"paged: page_size={engine.page_size} "
               f"pages={engine.num_pages} "

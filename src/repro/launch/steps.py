@@ -118,8 +118,9 @@ def make_train_step(cfg: ModelConfig, opt,
             grads = jax.tree_util.tree_unflatten(
                 treedef, [natural_compress(l, k)
                           for l, k in zip(leaves, keys)])
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        new_params, new_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, 1.0)
+            new_params, new_state = opt.update(grads, opt_state, params)
         return new_params, new_state, {"loss": loss, "gnorm": gnorm}
     return train_step
 

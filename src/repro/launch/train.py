@@ -19,7 +19,6 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +34,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (batch_abstract, batch_pspecs,
                                 make_train_step, named_tree)
 from repro.models import model as MD
-from repro.obs import recorder as obs
+from repro.obs.spans import span
 from repro.optim.optimizers import get_optimizer, warmup_cosine
 
 ENVS = {
@@ -177,28 +176,33 @@ def _train(args) -> dict:
                 save_checkpoint(args.ckpt_dir, at_step, tree, meta)
 
         losses = []
-        t0 = time.time()
+        step_s = []  # train.step seconds since the last log line
+        batches = iter(pipe)
         try:
-            for i, batch in enumerate(pipe.batches(args.steps)):
+            for i in range(args.steps):
                 step = step0 + i
-                dev_batch = {k: jax.device_put(v, bshard[k])
-                             for k, v in batch.items()}
+                with span("train.data", cat="train", step=step):
+                    batch = next(batches)
+                    dev_batch = {k: jax.device_put(v, bshard[k])
+                                 for k, v in batch.items()}
                 if cfg.arch_type in ("vlm", "audio"):
                     ee = batch_abs["extra_embeds"]
                     dev_batch["extra_embeds"] = jnp.zeros(ee.shape, ee.dtype)
                 extra = ((jax.random.PRNGKey(args.seed + 1 + step),)
                          if args.compress_grads else ())
-                with obs.get().span("train.step", cat="train", step=step):
+                with span("train.step", cat="train", step=step) as s:
                     params, opt_state, metrics = step_fn(params, opt_state,
                                                          dev_batch, *extra)
                     loss = float(metrics["loss"])
+                step_s.append(s.seconds)
                 losses.append(loss)
                 if step % args.log_every == 0:
-                    dt = time.time() - t0
                     print(f"step {step:5d} loss {loss:.4f} "
                           f"(floor~{entropy_floor:.3f}) "
                           f"gnorm {float(metrics['gnorm']):.3f} "
-                          f"{dt / max(i, 1):.2f}s/step", flush=True)
+                          f"{sum(step_s) / len(step_s):.2f}s/step",
+                          flush=True)
+                    step_s.clear()
                 if (args.ckpt_dir and args.ckpt_every
                         and (step + 1) % args.ckpt_every == 0):
                     _save(step + 1)

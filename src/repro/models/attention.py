@@ -79,6 +79,7 @@ def causal_mask(S: int, T: int, offset: int = 0,
     return m
 
 
+@jax.named_scope("attention")
 def attention(p, x, positions, cfg: ModelConfig, *,
               encoder_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
               causal: bool = True) -> jax.Array:
@@ -209,6 +210,7 @@ def _paged_gather(pool, bt, C):
     return pool[bt].reshape(B, -1, Hk, dh)[:, :C]
 
 
+@jax.named_scope("attention")
 def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
                      *, encoder_kv_cache=None, active=None,
                      block_tables=None, logical_len=None):
@@ -256,8 +258,9 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
                                    axis=1)[:, 0]  # (B,) physical page ids
         if active is not None:
             page = jnp.where(active, page, Np)  # OOB -> write dropped
-        new_k = cache_k.at[page, pos_b % P].set(k1[:, 0], mode="drop")
-        new_v = cache_v.at[page, pos_b % P].set(v1[:, 0], mode="drop")
+        with jax.named_scope("paged_cache_write"):
+            new_k = cache_k.at[page, pos_b % P].set(k1[:, 0], mode="drop")
+            new_v = cache_v.at[page, pos_b % P].set(v1[:, 0], mode="drop")
         if cfg.use_paged_kernel:
             from repro.kernels import ops as K
             out = K.paged_attention(q[:, 0], new_k, new_v, block_tables,
@@ -296,6 +299,7 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
     return shard(y, "batch", None, None), new_k, new_v
 
 
+@jax.named_scope("attention")
 def attention_verify(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
                      *, active=None, block_tables=None, logical_len=None):
     """Draft-verify attention: S candidate tokens per row in ONE pass.
@@ -327,8 +331,9 @@ def attention_verify(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
         page = jnp.take_along_axis(block_tables, qpos // P, axis=1)  # (B,S)
         if active is not None:
             page = jnp.where(active[:, None], page, Np)
-        new_k = cache_k.at[page, qpos % P].set(k1, mode="drop")
-        new_v = cache_v.at[page, qpos % P].set(v1, mode="drop")
+        with jax.named_scope("paged_cache_write"):
+            new_k = cache_k.at[page, qpos % P].set(k1, mode="drop")
+            new_v = cache_v.at[page, qpos % P].set(v1, mode="drop")
         k = _paged_gather(new_k, block_tables, C)
         v = _paged_gather(new_v, block_tables, C)
     else:

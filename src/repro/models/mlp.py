@@ -31,6 +31,7 @@ def mlp_descs(cfg: ModelConfig, d_ff: Optional[int] = None,
     return descs
 
 
+@jax.named_scope("mlp")
 def mlp(p, x, cfg: ModelConfig):
     if cfg.activation == "swiglu":
         h = jax.nn.silu(dense(x, p["w1"])) * dense(x, p["w3"])
@@ -65,6 +66,7 @@ def moe_capacity(cfg: ModelConfig, num_tokens: int) -> int:
     return max(cap, cfg.top_k)
 
 
+@jax.named_scope("mlp")
 def moe(p, x, cfg: ModelConfig, groups: Optional[int] = None):
     """x: (B,S,d) -> (y, aux_loss).  GShard-style GROUP-WISE routing.
 

@@ -225,8 +225,7 @@ def forward(params, cfg: ModelConfig, tokens, *, extra_embeds=None,
     else:
         raise ValueError(at)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = dense(x, params["lm_head"])
+    logits = _logits(params, cfg, x)
     logits = shard(logits, "batch", None, "model")
     if n_prefix:
         logits = logits[:, n_prefix:]
@@ -287,6 +286,13 @@ def _run_rwkv(params, cfg, x, return_cache):
     fn = jax.checkpoint(body) if cfg.remat == "block" else body
     (x, aux), ys = _scan(cfg, fn, (x, aux0), params["blocks"])
     return x, aux, ys
+
+
+@jax.named_scope("logits")
+def _logits(params, cfg: ModelConfig, x):
+    """Final norm and LM head: (B, S, d) -> (B, S, V)."""
+    return dense(rms_norm(x, params["final_norm"], cfg.norm_eps),
+                 params["lm_head"])
 
 
 # ---------------------------------------------------------------------------
@@ -378,8 +384,7 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, cache, *,
     else:
         raise ValueError(at)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = dense(x, params["lm_head"])
+    logits = _logits(params, cfg, x)
     return shard(logits, "batch", None, "model"), new_cache
 
 
@@ -473,8 +478,7 @@ def verify_step(params, cfg: ModelConfig, tokens, pos, cache, *,
     (x, _), (nk, nv) = _scan(cfg, body, (x, jnp.zeros((), jnp.float32)),
                              (params["blocks"], cache["k"], cache["v"]))
     new_cache = dict(cache, k=nk, v=nv)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = dense(x, params["lm_head"])
+    logits = _logits(params, cfg, x)
     return shard(logits, "batch", None, "model"), new_cache
 
 
@@ -551,6 +555,7 @@ def init_paged_cache(cfg: ModelConfig, num_slots: int, num_pages: int,
         paged_cache_specs(cfg, num_slots, num_pages, page_size))
 
 
+@jax.named_scope("paged_cache_write")
 def write_paged_cache(pool_cache, request_cache, slot, page_ids, cfg):
     """Install one request's B=1 prefill cache into a paged pool: KV
     leaves (prefilled to a page multiple) scatter whole pages onto the

@@ -21,9 +21,9 @@ Three phases, mirroring Chrome trace semantics (`recorder.Event`):
 * counter ("C") — a sampled registry value on the timeline.
 
 Alongside the timeline sits a flat metrics **registry** (dotted
-name -> value) fed by `count()`/`gauge()` and the `Counter`/`Gauge`
-handles; `repro.obs.registry.bench_report` rewrites benchmark JSON as a
-view over it.
+name -> value) fed by `count()`/`gauge()`;
+`repro.obs.registry.bench_report` rewrites benchmark JSON as a view over
+it.
 
 Clock sources
 -------------
@@ -52,6 +52,11 @@ Export surfaces
 * `repro.obs.registry.bench_report` — bench JSON from the registry.
 * `repro.obs.log` — the stdlib logger (`repro.*`) library code uses
   instead of print; WARNING-quiet by default, launchers `configure()`.
+* `repro.obs.spans.span` — the span that program code on the chip's
+  host opens: it records here and also enters a
+  `jax.profiler.TraceAnnotation`, so a profiler capture (`--profile-dir`
+  on the launchers) holds it on the device trace's clock. It imports
+  jax, so this package does not import it.
 
 The default recorder is a `NullRecorder`: every producer call is a
 no-op returning shared objects, so un-instrumented hot paths allocate
@@ -60,15 +65,15 @@ nothing (pinned by the counting-shim test). Enable with
 `--trace-out=PATH` on the launchers. Everything in this package is
 stdlib-only: worker subprocesses import it and must never load jax.
 """
-from repro.obs.recorder import (Counter, Event, Gauge, NullRecorder,
-                                Recorder, Span, get, install, recording)
+from repro.obs.recorder import (Event, NullRecorder, Recorder, Span, get,
+                                install, recording)
 from repro.obs.registry import bench_report, emit_metrics, registry_view
 from repro.obs.trace import chrome_trace, trace_json, write_trace
 from repro.obs.flight import FlightRecorder, load_flight
 from repro.obs import log
 
 __all__ = [
-    "Counter", "Event", "Gauge", "NullRecorder", "Recorder", "Span",
+    "Event", "NullRecorder", "Recorder", "Span",
     "get", "install", "recording",
     "bench_report", "emit_metrics", "registry_view",
     "chrome_trace", "trace_json", "write_trace",

@@ -91,40 +91,6 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class Counter:
-    """Monotonic handle bound to a recorder registry entry."""
-
-    __slots__ = ("name", "_rec")
-
-    def __init__(self, name: str, rec: Optional["Recorder"] = None):
-        self.name = name
-        self._rec = rec
-
-    def inc(self, delta: float = 1.0) -> None:
-        (self._rec or get()).count(self.name, delta)
-
-    @property
-    def value(self) -> float:
-        return (self._rec or get()).registry.get(self.name, 0.0)
-
-
-class Gauge:
-    """Last-value handle bound to a recorder registry entry."""
-
-    __slots__ = ("name", "_rec")
-
-    def __init__(self, name: str, rec: Optional["Recorder"] = None):
-        self.name = name
-        self._rec = rec
-
-    def set(self, value: Any) -> None:
-        (self._rec or get()).gauge(self.name, value)
-
-    @property
-    def value(self) -> Any:
-        return (self._rec or get()).registry.get(self.name)
-
-
 class Recorder:
     """Process-local event sink + metrics registry.
 
@@ -185,12 +151,6 @@ class Recorder:
             self._record(Event(self.clock(),
                                self.host if host is None else host,
                                "C", name, "gauge", 0.0, {"value": value}))
-
-    def counter(self, name: str) -> Counter:
-        return Counter(name, self)
-
-    def gauge_handle(self, name: str) -> Gauge:
-        return Gauge(name, self)
 
     def merge(self, events: List[Event]) -> None:
         """Adopt events recorded elsewhere (e.g. pulled worker rings)."""

@@ -55,8 +55,7 @@ length.  When the pool runs dry the engine preempts the most recently
 admitted slot into a prefix continuation (deterministic, oldest-work-
 first), so the pool can be sized for the average footprint.  The honest
 utilization number is `pool_occupancy` (pages in use / pool pages,
-reported next to the legacy slot occupancy as the
-``serving.pool_occupancy`` gauge).
+reported by `stats()` next to the slot `occupancy`).
 
 Paging also makes the KV cache a first-class migratable object: `drain()`
 harvests each live slot's pages host-side (`engine.MigratedKV`), and a

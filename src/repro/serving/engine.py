@@ -51,6 +51,7 @@ WHEN work runs, never WHAT each request computes.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -61,6 +62,7 @@ import numpy as np
 from repro.launch.steps import (make_paged_serve_cb_step, make_serve_cb_step,
                                 sharded_argmax)
 from repro.obs import recorder as obs
+from repro.obs.spans import span
 from repro.models import model as MD
 from repro.models.config import ModelConfig
 from repro.serving.request import (FinishedRequest, Request,
@@ -308,6 +310,12 @@ class ServeEngine:
         self._preempted: Dict[int, tuple] = {}
         self.ticks = 0
         self.decode_ticks = 0
+        self.decode_chunks = 0
+        self.emitted_tokens = 0  # every token harvested, finished or not
+        # host seconds by part of the work between device programs
+        # (`_host_part`): what a decode tick costs the host beyond the chip
+        self._host_s = dict.fromkeys(
+            ("coverage", "dispatch", "wait", "harvest", "prefill"), 0.0)
         self.prefill_ticks = 0
         self.prefill_tokens = 0
         self.migrated_admits = 0
@@ -333,31 +341,33 @@ class ServeEngine:
     def _bt_dev(self):
         return jnp.asarray(self.block_tables)
 
+    @contextlib.contextmanager
+    def _host_part(self, part: str):
+        """Span `serve.<part>` (obs recorder and profiler trace), whose
+        host seconds add to `host_<part>_s` in `stats()`."""
+        with span("serve." + part, host=self.host, cat="serving") as s:
+            yield
+        self._host_s[part] += s.seconds
+
     def _admit(self, req: Request, slot: int) -> None:
         if self.paged and req.kv_seed is not None:
             self._admit_migrated(req, slot)
             return
-        prompt = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
-        start_pos = prompt.shape[1] + self.n_prefix
-        if self.paged:
-            npg = self.pages.pages_for(start_pos + 1)
-            page_ids = self.pages.alloc(slot, npg)
-            assert page_ids is not None, "admission gate checked pages"
-            self.block_tables[slot, :npg] = page_ids
+        with self._host_part("prefill"):
+            prompt = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
+            start_pos = prompt.shape[1] + self.n_prefix
+            pages = ()
+            if self.paged:
+                npg = self.pages.pages_for(start_pos + 1)
+                page_ids = self.pages.alloc(slot, npg)
+                assert page_ids is not None, "admission gate checked pages"
+                self.block_tables[slot, :npg] = page_ids
+                pages = (jnp.asarray(page_ids, jnp.int32),)
             (first, self.cache, self.tokens, self.pos_d, self.active_d,
              self.gen_d, self.maxgen_d, self.eos_d) = self.program.admit(
                 self.params, prompt, req.extra_embeds, self.cache,
                 self.tokens, self.pos_d, self.active_d, self.gen_d,
-                self.maxgen_d, self.eos_d, jnp.int32(slot),
-                jnp.asarray(page_ids, jnp.int32), jnp.int32(start_pos),
-                jnp.int32(req.max_new_tokens),
-                jnp.int32(-1 if req.eos_id is None else req.eos_id))
-        else:
-            (first, self.cache, self.tokens, self.pos_d, self.active_d,
-             self.gen_d, self.maxgen_d, self.eos_d) = self.program.admit(
-                self.params, prompt, req.extra_embeds, self.cache,
-                self.tokens, self.pos_d, self.active_d, self.gen_d,
-                self.maxgen_d, self.eos_d, jnp.int32(slot),
+                self.maxgen_d, self.eos_d, jnp.int32(slot), *pages,
                 jnp.int32(start_pos), jnp.int32(req.max_new_tokens),
                 jnp.int32(-1 if req.eos_id is None else req.eos_id))
         self.pool.occupy(slot, req, start_pos, self.ticks)
@@ -438,6 +448,7 @@ class ServeEngine:
         """Host mirror of the device retirement rule for one token."""
         req = self.pool.request[slot]
         self.pool.generated[slot].append(tok)
+        self.emitted_tokens += 1
         if len(self.pool.generated[slot]) == 1:
             obs.get().event("serve.first_token", host=self.host,
                             cat="serving", rid=req.rid)
@@ -538,35 +549,42 @@ class ServeEngine:
         """One fused k-tick dispatch, one host sync.  k = the largest power
         of two <= the smallest remaining budget (so budget retirements land
         on chunk boundaries and only a handful of chunk lengths ever
-        compile), capped at chunk_cap."""
+        compile), capped at chunk_cap.
+
+        Spans: `serve.decode` holds `serve.coverage` (page growth),
+        `serve.dispatch` (block-table upload and the chunk call),
+        `serve.wait` (the host blocked on the device for the token block)
+        and `serve.harvest` (the host's walk over the block)."""
         m = min(min(remaining), self.chunk_cap)
         k = 1 << (m.bit_length() - 1)
         fn = self.program.chunk(k)
-        if self.paged:
-            self._ensure_coverage(k)
-            if not self.pool.num_active and not self._pending_first:
-                return  # coverage preempted the whole pool
-            self._page_steps += self.pages.pages_in_use * k
-            (self.tokens, self.cache, self.pos_d, self.active_d, self.gen_d,
-             T, A) = fn(self.params, self.cache, self.tokens, self.pos_d,
-                        self.active_d, self.gen_d, self.maxgen_d,
-                        self.eos_d, self._bt_dev())
-        else:
-            (self.tokens, self.cache, self.pos_d, self.active_d, self.gen_d,
-             T, A) = fn(self.params, self.cache, self.tokens, self.pos_d,
-                        self.active_d, self.gen_d, self.maxgen_d,
-                        self.eos_d)
-        self.decode_ticks += k
-        # single harvest: (k,B) token block + the per-tick active masks
-        T = np.asarray(T)
-        A = np.asarray(A)
-        self._occupied_slot_steps += int(A.sum())
-        self._harvest_pending()
-        for t in range(k):
-            for slot in np.flatnonzero(A[t]):
-                slot = int(slot)
-                if self.pool.active[slot]:
-                    self._consume(slot, int(T[t, slot]))
+        with span("serve.decode", host=self.host, cat="serving", k=k):
+            if self.paged:
+                with self._host_part("coverage"):
+                    self._ensure_coverage(k)
+                if not self.pool.num_active and not self._pending_first:
+                    return  # coverage preempted the whole pool
+                self._page_steps += self.pages.pages_in_use * k
+            with self._host_part("dispatch"):
+                tables = (self._bt_dev(),) if self.paged else ()
+                (self.tokens, self.cache, self.pos_d, self.active_d,
+                 self.gen_d, T, A) = fn(self.params, self.cache, self.tokens,
+                                        self.pos_d, self.active_d, self.gen_d,
+                                        self.maxgen_d, self.eos_d, *tables)
+            self.decode_chunks += 1
+            self.decode_ticks += k
+            # single harvest: (k,B) token block + the per-tick active masks
+            with self._host_part("wait"):
+                T = np.asarray(T)
+                A = np.asarray(A)
+            with self._host_part("harvest"):
+                self._occupied_slot_steps += int(A.sum())
+                self._harvest_pending()
+                for t in range(k):
+                    for slot in np.flatnonzero(A[t]):
+                        slot = int(slot)
+                        if self.pool.active[slot]:
+                            self._consume(slot, int(T[t, slot]))
 
     # ------------------------------------------------------------------
     def _next_admission(self):
@@ -730,17 +748,19 @@ class ServeEngine:
         return self._page_steps / (self.decode_ticks * self.num_pages)
 
     def stats(self) -> Dict[str, float]:
+        """Cumulative counters since `reset()`.  `decode_chunks` counts
+        chunk dispatches (`decode_ticks / decode_chunks` ticks each);
+        `host_<part>_s` are the host seconds of each `serve.<part>` span;
+        `emitted_tokens` counts every token harvested, finished or not."""
         gen_tokens = sum(len(f.tokens) for f in self.finished)
-        rec = obs.get()
-        if rec.enabled:
-            rec.gauge("serving.slot_occupancy", self.occupancy)
-            if self.paged:
-                rec.gauge("serving.pool_occupancy", self.pool_occupancy)
         out = {"ticks": self.ticks, "decode_ticks": self.decode_ticks,
+               "decode_chunks": self.decode_chunks,
                "prefill_ticks": self.prefill_ticks,
                "prefill_tokens": self.prefill_tokens,
                "occupancy": self.occupancy,
-               "generated_tokens": gen_tokens}
+               "generated_tokens": gen_tokens,
+               "emitted_tokens": self.emitted_tokens}
+        out.update({f"host_{k}_s": v for k, v in self._host_s.items()})
         if self.paged:
             out.update({"pool_occupancy": self.pool_occupancy,
                         "num_pages": self.num_pages,

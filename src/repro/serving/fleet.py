@@ -558,9 +558,6 @@ class ServeFleet:
                 + sum(e.preemptions for e in engines),
                 "pool_occupancy": page_steps / max(tick_pages, 1),
             })
-            if rec.enabled:
-                rec.gauge("serving.pool_occupancy",
-                          out["pool_occupancy"])
         if self.hedged_decode:
             out.update({"hedges_launched": self.hedges_launched,
                         "hedges_won_primary": self.hedges_won_primary,
