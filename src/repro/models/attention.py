@@ -195,8 +195,16 @@ def paged_kv_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
             "v": jax.ShapeDtypeStruct(shape, dtype)}
 
 
-def _paged_gather(pool, bt, C):
-    """pool: (Np,P,Hk,dh); bt: (B,n_max) page ids -> (B,C,Hk,dh) view.
+def _paged_at(layer, *idx):
+    """Index into a paged pool: `idx` into one layer's pool (Np,P,Hk,dh)
+    when layer is None, else `(layer, *idx)` into the stacked pools
+    (L,Np,P,Hk,dh) of every layer."""
+    return idx if layer is None else (layer,) + idx
+
+
+def _paged_gather(pool, bt, C, layer=None):
+    """pool: (Np,P,Hk,dh), or the stacked (L,Np,P,Hk,dh) read at `layer`;
+    bt: (B,n_max) page ids -> (B,C,Hk,dh) view.
 
     The gathered view is bit-identical to a dense (B,C) cache on every
     position < the row's logical length: page j of row b holds positions
@@ -206,13 +214,13 @@ def _paged_gather(pool, bt, C):
     never perturb the output (the bit-identity argument the paged engine
     rests on)."""
     B = bt.shape[0]
-    Hk, dh = pool.shape[2], pool.shape[3]
-    return pool[bt].reshape(B, -1, Hk, dh)[:, :C]
+    Hk, dh = pool.shape[-2:]
+    return pool[_paged_at(layer, bt)].reshape(B, -1, Hk, dh)[:, :C]
 
 
 @jax.named_scope("attention")
 def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
-                     *, encoder_kv_cache=None, active=None,
+                     *, layer=None, encoder_kv_cache=None, active=None,
                      block_tables=None, logical_len=None):
     """x: (B,1,d); cache_k/v: (B,C,Hk,dh); pos: () int32 current length,
     or (B,) int32 — one position per batch row, so slots of a continuous-
@@ -227,6 +235,13 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
     pool[block_tables[b, q // P], q % P].  logical_len bounds the gathered
     view (static; = the dense cache_len it replaces).  Requires vector pos;
     ring buffers (sliding window) do not compose with paging.
+
+    layer: optional int32 scalar (paged mode) — cache_k/v are then the
+    STACKED pools (L, Np, P, Hk, dh) of every layer, this layer's token is
+    written at pool[layer, page, q % P] and the gather reads pool[layer,
+    block_tables]; the updated stacked pools are returned.  A caller that
+    carries the stacked pools through its layer scan this way updates them
+    in place, with no per-layer slice or restack of the pool.
 
     Returns (y, new_cache_k, new_cache_v).  With a sliding window the cache
     is a ring buffer of size C=window; otherwise C >= pos+1.
@@ -253,22 +268,25 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
         new_k, new_v = cache_k, cache_v
     elif paged:
         q, k1, v1 = _project_qkv(p, x, positions, cfg)
-        Np, P = cache_k.shape[0], cache_k.shape[1]
+        Np, P = cache_k.shape[-4], cache_k.shape[-3]
         page = jnp.take_along_axis(block_tables, (pos_b // P)[:, None],
                                    axis=1)[:, 0]  # (B,) physical page ids
         if active is not None:
             page = jnp.where(active, page, Np)  # OOB -> write dropped
+        at = _paged_at(layer, page, pos_b % P)
         with jax.named_scope("paged_cache_write"):
-            new_k = cache_k.at[page, pos_b % P].set(k1[:, 0], mode="drop")
-            new_v = cache_v.at[page, pos_b % P].set(v1[:, 0], mode="drop")
+            new_k = cache_k.at[at].set(k1[:, 0], mode="drop")
+            new_v = cache_v.at[at].set(v1[:, 0], mode="drop")
         if cfg.use_paged_kernel:
             from repro.kernels import ops as K
-            out = K.paged_attention(q[:, 0], new_k, new_v, block_tables,
+            lk, lv = ((new_k, new_v) if layer is None
+                      else (new_k[layer], new_v[layer]))
+            out = K.paged_attention(q[:, 0], lk, lv, block_tables,
                                     pos_b, logical_len=C)[:, None]
             y = dense(out.reshape(B, 1, -1), p["wo"])
             return shard(y, "batch", None, None), new_k, new_v
-        k = _paged_gather(new_k, block_tables, C)
-        v = _paged_gather(new_v, block_tables, C)
+        k = _paged_gather(new_k, block_tables, C, layer)
+        v = _paged_gather(new_v, block_tables, C, layer)
         valid = jnp.arange(C)[None, :] <= pos_b[:, None]  # (B,C)
     else:
         q, k1, v1 = _project_qkv(p, x, positions, cfg)
@@ -301,7 +319,8 @@ def attention_decode(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
 
 @jax.named_scope("attention")
 def attention_verify(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
-                     *, active=None, block_tables=None, logical_len=None):
+                     *, layer=None, active=None, block_tables=None,
+                     logical_len=None):
     """Draft-verify attention: S candidate tokens per row in ONE pass.
 
     x: (B,S,d) — row b's tokens sit at positions pos[b] .. pos[b]+S-1.
@@ -313,7 +332,8 @@ def attention_verify(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
     query can see them (pos' <= pos + S), so no rollback write is needed —
     rolling back IS just not advancing `pos`.
 
-    Dense cache (B,C,Hk,dh) or paged pool + block_tables, as in
+    Dense cache (B,C,Hk,dh) or paged pool + block_tables (one layer's, or
+    the stacked pools read and written at `layer`), as in
     `attention_decode`.  Returns (y (B,S,d), new_k, new_v)."""
     B, S, _ = x.shape
     paged = block_tables is not None
@@ -327,15 +347,16 @@ def attention_verify(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
     qpos = pos[:, None] + jnp.arange(S)[None, :]  # (B,S) global positions
     q, k1, v1 = _project_qkv(p, x, qpos, cfg)
     if paged:
-        Np, P = cache_k.shape[0], cache_k.shape[1]
+        Np, P = cache_k.shape[-4], cache_k.shape[-3]
         page = jnp.take_along_axis(block_tables, qpos // P, axis=1)  # (B,S)
         if active is not None:
             page = jnp.where(active[:, None], page, Np)
+        at = _paged_at(layer, page, qpos % P)
         with jax.named_scope("paged_cache_write"):
-            new_k = cache_k.at[page, qpos % P].set(k1, mode="drop")
-            new_v = cache_v.at[page, qpos % P].set(v1, mode="drop")
-        k = _paged_gather(new_k, block_tables, C)
-        v = _paged_gather(new_v, block_tables, C)
+            new_k = cache_k.at[at].set(k1, mode="drop")
+            new_v = cache_v.at[at].set(v1, mode="drop")
+        k = _paged_gather(new_k, block_tables, C, layer)
+        v = _paged_gather(new_v, block_tables, C, layer)
     else:
         rows = jnp.arange(B)[:, None]
         slot = qpos

@@ -32,6 +32,25 @@ def _scan(cfg, fn, carry, xs):
     return jax.lax.scan(fn, carry, xs, unroll=max(unroll, 1))
 
 
+def _scan_kv(cfg, fn, carry, xs, kv, *, in_carry: bool):
+    """Scan `fn(carry, xs, kv, layer) -> (carry, kv)` over the layers.
+
+    in_carry (paged pools): the stacked KV `kv` travels in the scan's carry
+    beside the int32 layer index, so each layer writes its tokens into the
+    stacked buffers in place; no layer slices, restacks or copies a pool.
+    Otherwise (per-slot caches) each layer gets its own slice of `kv` as
+    xs, with layer None, and returns it as ys.  Returns (carry, new kv)."""
+    if in_carry:
+        def body(c, x):
+            c, layer, kv = c
+            c, kv = fn(c, x, kv, layer)
+            return (c, layer + 1, kv), None
+        (carry, _, kv), _ = _scan(
+            cfg, body, (carry, jnp.zeros((), jnp.int32), kv), xs)
+        return carry, kv
+    return _scan(cfg, lambda c, x: fn(c, x[0], x[1], None), carry, (xs, kv))
+
+
 # ---------------------------------------------------------------------------
 # Parameter descriptor trees
 # ---------------------------------------------------------------------------
@@ -319,7 +338,12 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, cache, *,
     leaves (`paged_leaf_names`) are shared page pools (stack, Np, P, Hk,
     dh) and row b reads/writes through its block table; every other leaf
     (audio cross-KV, hybrid recurrent state) stays per-slot.  logical_len
-    is the static dense cache_len the pool replaces.
+    is the static dense cache_len the pool replaces.  The layer scan
+    carries the stacked pools with the layer index: each layer's
+    `attention_decode` takes the stacked pools and its index and returns
+    the updated stacked pools, so the pools are written in place (no
+    per-layer slice, restack or copy); per-slot leaves are the scan's
+    xs/ys.
 
     Returns (logits (B,1,V), new cache)."""
     at = cfg.arch_type
@@ -333,16 +357,13 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, cache, *,
     x = shard(x, "batch", None, None)
 
     if at in ("dense", "vlm", "moe", "audio"):
-        def body(carry, xs):
+        def body(carry, xs, kv, layer):
             h, aux = carry
-            if at == "audio":
-                lp, ck, cv, xk, xv = xs
-            else:
-                lp, ck, cv = xs
-                xk = xv = None
+            lp, xkv = xs  # xkv: audio's per-slot cross-KV (ck, cv), else None
+            ck, cv = kv
             pre = rms_norm(h, lp["ln1"], cfg.norm_eps)
             y, nk, nv = A.attention_decode(lp["attn"], pre, ck, cv, pos, cfg,
-                                           active=active,
+                                           layer=layer, active=active,
                                            block_tables=block_tables,
                                            logical_len=logical_len)
             h = h + y
@@ -350,7 +371,7 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, cache, *,
                 hc = rms_norm(h, lp["lnc"], cfg.norm_eps)
                 yc, _, _ = A.attention_decode(
                     lp["cross"], hc, ck * 0, cv * 0, pos, cfg,
-                    encoder_kv_cache=(xk, xv))
+                    encoder_kv_cache=xkv)
                 h = h + yc
             pre2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
             if at == "moe":
@@ -360,10 +381,11 @@ def decode_step(params, cfg: ModelConfig, tokens, pos, cache, *,
                 h = h + M.mlp(lp["mlp"], pre2, cfg)
             return (h, aux), (nk, nv)
 
-        xs = (params["blocks"], cache["k"], cache["v"])
-        if at == "audio":
-            xs = xs + (cache["ck"], cache["cv"])
-        (x, _), (nk, nv) = _scan(cfg, body, (x, jnp.zeros((), jnp.float32)), xs)
+        xkv = (cache["ck"], cache["cv"]) if at == "audio" else None
+        (x, _), (nk, nv) = _scan_kv(
+            cfg, body, (x, jnp.zeros((), jnp.float32)),
+            (params["blocks"], xkv), (cache["k"], cache["v"]),
+            in_carry=block_tables is not None)
         new_cache = dict(cache, k=nk, v=nv)
 
     elif at == "hybrid":
@@ -458,12 +480,12 @@ def verify_step(params, cfg: ModelConfig, tokens, pos, cache, *,
         jnp.dtype(cfg.compute_dtype))
     x = shard(x, "batch", None, None)
 
-    def body(carry, xs):
+    def body(carry, lp, kv, layer):
         h, aux = carry
-        lp, ck, cv = xs
+        ck, cv = kv
         pre = rms_norm(h, lp["ln1"], cfg.norm_eps)
         y, nk, nv = A.attention_verify(lp["attn"], pre, ck, cv, pos, cfg,
-                                       active=active,
+                                       layer=layer, active=active,
                                        block_tables=block_tables,
                                        logical_len=logical_len)
         h = h + y
@@ -475,8 +497,9 @@ def verify_step(params, cfg: ModelConfig, tokens, pos, cache, *,
             h = h + M.mlp(lp["mlp"], pre2, cfg)
         return (h, aux), (nk, nv)
 
-    (x, _), (nk, nv) = _scan(cfg, body, (x, jnp.zeros((), jnp.float32)),
-                             (params["blocks"], cache["k"], cache["v"]))
+    (x, _), (nk, nv) = _scan_kv(cfg, body, (x, jnp.zeros((), jnp.float32)),
+                                params["blocks"], (cache["k"], cache["v"]),
+                                in_carry=block_tables is not None)
     new_cache = dict(cache, k=nk, v=nv)
     logits = _logits(params, cfg, x)
     return shard(logits, "batch", None, "model"), new_cache

@@ -11,7 +11,8 @@ import pytest
 from repro.configs import InputShape, get_config
 from repro.models import model as MD
 from repro.launch.steps import sharded_argmax
-from repro.serving import Request, ServeEngine
+from repro.serving import Request, ServeEngine, ServeProgram
+from tests._hlo import pool_traffic
 
 KEY = jax.random.PRNGKey(0)
 
@@ -287,6 +288,110 @@ def test_paged_decode_step_matches_dense(arch):
                                     block_tables=bt, logical_len=C)
         np.testing.assert_array_equal(np.asarray(l_d), np.asarray(l_p))
         pos = pos + 1
+
+
+def _chunk_inputs(cfg, params, B, P, n_max, npages, lens, active):
+    """Prefilled dense cache, paged pool (sentinel 7.0 on every page no
+    prefill wrote; scrambled disjoint block tables) and the chunk's lifecycle
+    registers for slots with prompt lengths `lens`."""
+    C = n_max * P
+    toks = jax.random.randint(KEY, (B, max(lens) + 1), 0, cfg.vocab_size)
+    dense = MD.init_cache(cfg, B, C)
+    paged = jax.tree_util.tree_map(lambda a: jnp.full_like(a, 7.0),
+                                   MD.init_paged_cache(cfg, B, npages, P))
+    ids = np.random.RandomState(3).permutation(npages)[:B * n_max]
+    ids = ids.reshape(B, n_max).astype(np.int32)
+    for b, n in enumerate(lens):
+        npg = -(-n // P)
+        _, _, c1 = MD.forward(params, cfg, toks[b:b + 1, :n],
+                              return_cache=True, cache_len=C)
+        dense = MD.write_cache_slot(dense, c1, b)
+        _, _, c2 = MD.forward(params, cfg, toks[b:b + 1, :n],
+                              return_cache=True, cache_len=npg * P)
+        paged = MD.write_paged_cache(paged, c2, b,
+                                     jnp.asarray(ids[b, :npg]), cfg)
+    regs = (toks[jnp.arange(B), jnp.asarray(lens)][:, None],     # tokens
+            jnp.asarray(lens, jnp.int32),                       # pos
+            jnp.asarray(active),                                # active
+            jnp.zeros((B,), jnp.int32),                         # gen
+            jnp.full((B,), 100, jnp.int32),                     # maxgen
+            jnp.full((B,), -1, jnp.int32))                      # eos
+    return dense, paged, jnp.asarray(ids), regs
+
+
+def test_paged_chunk_writes_pool_in_place():
+    """The paged decode chunk carries the stacked KV pools through the
+    layer scan: no copy, dynamic-slice, dynamic-update-slice or broadcast
+    makes a value shaped like a pool or one layer of it, and one scatter
+    per KV leaf writes the pool."""
+    cfg = _cfg("qwen3-0.6b")
+    B, P, n_max, npages = 3, 4, 5, 17      # gathered view 3x20: no clash
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    cache = jax.tree_util.tree_map(
+        sds, MD.paged_cache_specs(cfg, B, npages, P))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    args = (MD.model_abstract(cfg), cache, i32(B, 1), i32(B),
+            jax.ShapeDtypeStruct((B,), jnp.bool_), i32(B), i32(B), i32(B),
+            i32(B, n_max))
+    prog = ServeProgram(cfg, cache_len=n_max * P, page_size=P)
+    text = prog.chunk(4).lower(*args).compile().as_text()
+    moves, writes = pool_traffic(text, cache["k"].shape)
+    assert moves == []
+    assert writes == len(MD.paged_leaf_names(cfg))
+
+
+def test_paged_chunk_pool_matches_dense_cache():
+    """After one chunk of ticks the pool holds, at every (slot, position)
+    below the slot's length, exactly the K/V the dense cache holds — across
+    a page boundary crossed inside the chunk — and nothing else in the pool
+    changed: a retired slot's writes are dropped."""
+    cfg = _cfg("qwen3-0.6b")
+    params = MD.init_model(cfg, KEY)
+    B, P, n_max, npages, k = 3, 4, 4, 15, 4
+    lens = [6, 3, 5]                       # slot 0 crosses 8, slot 1 crosses 4
+    active = [True, True, False]           # slot 2 is retired
+    dense, paged, bt, regs = _chunk_inputs(cfg, params, B, P, n_max, npages,
+                                           lens, active)
+    before = {n: np.asarray(paged[n]) for n in MD.paged_leaf_names(cfg)}
+    C = n_max * P
+    out_d = ServeProgram(cfg, cache_len=C).chunk(k)(params, dense, *regs)
+    out_p = ServeProgram(cfg, cache_len=C, page_size=P).chunk(k)(
+        params, paged, *regs, bt)
+    np.testing.assert_array_equal(np.asarray(out_d[5]), np.asarray(out_p[5]))
+    pos = np.asarray(out_p[2])
+    assert list(pos) == [lens[0] + k, lens[1] + k, lens[2]]
+    ids = np.asarray(bt)
+    for name in MD.paged_leaf_names(cfg):
+        want = before[name].copy()
+        got, dref = np.asarray(out_p[1][name]), np.asarray(out_d[1][name])
+        for b in range(B):
+            for q in range(pos[b]):
+                want[:, ids[b, q // P], q % P] = dref[:, b, q]
+        np.testing.assert_array_equal(got, want)
+
+
+def test_paged_kernel_decode_reads_its_layer():
+    """With `use_paged_kernel`, the carried stacked pools reach the Pallas
+    kernel one layer at a time: logits and pools agree with the gather
+    path up to float32 reduction order, and layer 0's write, which no
+    attention precedes, is bit-identical."""
+    cfg = _cfg("qwen3-0.6b")
+    params = MD.init_model(cfg, KEY)
+    B, P, n_max, npages = 3, 4, 4, 15
+    lens = [6, 3, 5]
+    _, paged, bt, regs = _chunk_inputs(cfg, params, B, P, n_max, npages,
+                                       lens, [True, True, False])
+    tok, pos, active = regs[:3]
+    outs = [MD.decode_step(params, c, tok, pos, paged, active=active,
+                           block_tables=bt, logical_len=n_max * P)
+            for c in (cfg, cfg.with_(use_paged_kernel=True))]
+    (l_g, c_g), (l_k, c_k) = outs
+    np.testing.assert_allclose(np.asarray(l_k), np.asarray(l_g),
+                               rtol=1e-5, atol=1e-5)
+    for name in MD.paged_leaf_names(cfg):
+        k, g = np.asarray(c_k[name]), np.asarray(c_g[name])
+        np.testing.assert_array_equal(k[0], g[0])
+        np.testing.assert_allclose(k, g, rtol=1e-5, atol=1e-5)
 
 
 def test_paged_decode_rejects_recurrent_cache():

@@ -1,5 +1,6 @@
 """The main-path Pallas kernels compile for a TPU v5e chip (Mosaic, not
-interpret mode), at qwen3-0.6b widths.
+interpret mode), at qwen3-0.6b widths; so does the paged decode chunk,
+whose KV pools the chip's compiler must update in place.
 
 Nothing runs: the chip is described (`topologies.get_topology_desc`), not
 attached, and each test lowers and compiles one kernel for it.  This finds
@@ -19,6 +20,9 @@ from repro.configs import get_config
 from repro.kernels import flash_attention as FA
 from repro.kernels import nat_compress as NC
 from repro.kernels import paged_attention as PA
+from repro.models import model as MD
+from repro.serving.engine import ServeProgram
+from tests._hlo import pool_traffic
 
 CFG = get_config("qwen3-0.6b")
 HQ, HK, DH = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
@@ -81,3 +85,22 @@ def test_nc_pack_compiles(sds):
 
 def test_nc_unpack_compiles(sds):
     _compiled_kernel(NC.nc_unpack, sds((CFG.d_model, CFG.d_ff), jnp.uint8))
+
+
+def test_paged_chunk_updates_pool_in_place(sds):
+    """The serving engine's paged decode chunk at qwen3-0.6b's full depth
+    and widths (a small pool): no pool- or layer-shaped copy, slice,
+    restack or broadcast; one scatter per KV leaf writes the pool."""
+    B, P, n_max, Np = 8, 16, 16, 160
+    as_sds = lambda t: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), t)
+    cache = as_sds(MD.paged_cache_specs(CFG, B, Np, P))
+    row = sds((B,), jnp.int32)
+    args = (as_sds(MD.model_abstract(CFG)), cache, sds((B, 1), jnp.int32),
+            row, sds((B,), jnp.bool_), row, row, row,
+            sds((B, n_max), jnp.int32))
+    prog = ServeProgram(CFG, cache_len=n_max * P, page_size=P)
+    text = prog.chunk(8).lower(*args).compile().as_text()
+    moves, writes = pool_traffic(text, cache["k"].shape)
+    assert moves == []
+    assert writes == 2
