@@ -9,6 +9,11 @@ file of its own, found by name:
     bench/traffic/<mix>.json        parameters of `traffic.py`'s generator
     bench/metrics/<metric>.py       read(ctx) -> number or None
     bench/drivers/<driver>.py       set-up, window, check for one kind of job
+    bench/families/<family>.py      the program's config and counts of a family
+    bench/reference/<family>.py     its plain reference
+
+What a family's two modules must provide is written once, in
+bench/families/__init__.py.
 """
 from __future__ import annotations
 
@@ -50,8 +55,26 @@ def load_driver(name: str):
     return importlib.import_module(f"bench.drivers.{name}")
 
 
+def _load_named(kind: str, family: str):
+    """bench/<kind>/<family>.py by module name; a missing file is an
+    error that names it."""
+    name = f"bench.{kind}.{family}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise ModuleNotFoundError(
+            f"family {family!r} has no bench/{kind}/{family}.py (the "
+            f"contract is in bench/families/__init__.py)", name=name) from None
+
+
+def load_family(family: str):
+    return _load_named("families", family)
+
+
 def load_reference(family: str):
-    return importlib.import_module(f"bench.reference.{family}")
+    return _load_named("reference", family)
 
 
 def cell_metrics(bench: Dict[str, Any], cell: str, trace: bool) -> List[dict]:

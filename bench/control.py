@@ -10,8 +10,9 @@ three steps, and the reference compares them.  Serving cells run the
 cell's own window and load, then compare the program's sample and the
 control's.  Controls: `bf16`, `fp8` (the reference at that precision in
 the program's place), `half` (training: half of the batch left out),
-`altered` (serving: one served token changed where produced).  One JSON
-line per seed.
+`exchange` (training on a mesh: the reference with no gradient exchange
+between the cell's data shards), `altered` (serving: one served token
+changed where produced).  One JSON line per seed.
 """
 import time
 

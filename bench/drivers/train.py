@@ -65,7 +65,9 @@ def change_norms(params, ref, c, seed, dtype, norm_fn) -> Dict[str, float]:
     diff = jax.jit(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32))
     out = {}
     for idx, (path, _, _) in enumerate(ref.leaf_table(c)):
-        live = ref._get(params, path)
+        live = params
+        for k in path:
+            live = live[k]
         w0 = ref.make_leaf(c, seed, idx, dtype, live.sharding)
         d = diff(live, w0)
         del w0
@@ -188,6 +190,9 @@ def run(env: Dict[str, Any]) -> Dict[str, Any]:
         if mode == "half":  # half of the batch left out, mean over the rest
             out = ref.train_reference(c, seed, rows, hyp, "f32", rmesh,
                                       rows_used=0.5)
+        elif mode == "exchange":  # no gradient exchange between data shards
+            out = ref.train_reference(c, seed, rows, hyp, "f32", rmesh,
+                                      local_groups=work["mesh"]["data"])
         else:               # the reference at a lower precision
             out = ref.train_reference(c, seed, rows, hyp, mode, rmesh)
         controls[mode] = correct.train_numbers(out, ref_out)

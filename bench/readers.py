@@ -35,3 +35,18 @@ def idle_share(ctx) -> Optional[float]:
     if not s:
         return None
     return 1.0 - s["busy_s"] / s["window_s"]
+
+
+def collective_exposed_share(ctx) -> Optional[float]:
+    """Collective time on each chip during which no other operation ran
+    there (a loop's event, which spans its body, does not count), over the
+    traced window, mean over the chips; None where the trace holds no
+    collective."""
+    s = ctx.get("trace")
+    if not s:
+        return None
+    devs = s["per_device"].values()
+    if not any(d["collective_s"] for d in devs):
+        return None
+    return (sum(d["collective_exposed_s"] for d in devs)
+            / len(devs) / s["window_s"])

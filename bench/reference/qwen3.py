@@ -286,18 +286,19 @@ class Trainer:
     nothing else on the device(s).  One step is two passes over the
     layers: the first finds the global gradient norm (for clipping), the
     second applies the update with it.  `mesh` spreads weights over its one
-    axis `x` and rows of the batch over the same axis."""
+    axis `x` and rows of the batch over the same axis.  `rows_used` and
+    `local_groups` are controls: part of the batch left out, and the
+    gradient exchange between data shards left out (`_local_step`)."""
 
-    def __init__(self, c, seed, hyper, mode="f32", mesh=None, rows_used=1.0):
+    def __init__(self, c, seed, hyper, mode="f32", mesh=None, rows_used=1.0,
+                 local_groups=1):
         self.c, self.h, self.mode = c, hyper, mode
         self.mesh = mesh
         self.rows_used = rows_used
+        self.local_groups = local_groups
         pdt = jnp.bfloat16 if mode == "bf16" else jnp.float32
         self.pdt = pdt
-        w = make_weights(c, seed, pdt)
-        shard = self._weight_sharding if mesh is not None else None
-        self.top, self.layers = split_layers(c, w, shard)
-        del w
+        self.top, self.layers = self._seed_weights(seed)
         if pdt == jnp.bfloat16:
             cast = lambda t: jax.tree_util.tree_map(
                 lambda a: a.astype(jnp.bfloat16), t)
@@ -370,12 +371,30 @@ class Trainer:
         self._gather = jax.jit(lambda e, t: jnp.take(e, t, axis=0)
                                .astype(jnp.float32))
 
-    def _weight_sharding(self, a):
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    def _spec(self, shape):
+        from jax.sharding import PartitionSpec as P
         n = self.mesh.shape["x"]
-        if a.ndim >= 2 and a.shape[0] % n == 0:
-            return NamedSharding(self.mesh, P("x"))
-        return NamedSharding(self.mesh, P())
+        return P("x") if len(shape) >= 2 and shape[0] % n == 0 else P()
+
+    def _weight_sharding(self, a):
+        from jax.sharding import NamedSharding
+        return NamedSharding(self.mesh, self._spec(a.shape))
+
+    def _seed_weights(self, seed):
+        """(top, layers) at the seed, float32.  On a mesh the stacked tree
+        is made with each leaf sharded as the trainer's live leaf is (one
+        layer of a stacked leaf like one layer), so that no device holds
+        more than its share of it."""
+        if self.mesh is None:
+            return split_layers(self.c, make_weights(self.c, seed, self.pdt))
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        shardings: Dict[str, Any] = {}
+        for path, shape, _ in leaf_table(self.c):
+            spec = (P(None, *self._spec(shape[1:])) if path[0] == "blocks"
+                    else self._spec(shape))
+            _set(shardings, path, NamedSharding(self.mesh, spec))
+        w = make_weights(self.c, seed, self.pdt, shardings)
+        return split_layers(self.c, w, self._weight_sharding)
 
     def _rows(self, x):
         if self.mesh is None:
@@ -405,11 +424,10 @@ class Trainer:
         if self.rows_used < 1.0:
             keep = max(1, int(round(tokens.shape[0] * self.rows_used)))
             tokens, labels = tokens[:keep], labels[:keep]
+        if self.local_groups > 1:
+            return self._local_step(tokens, labels)
         tokens, labels = self._rows(tokens), self._rows(labels)
-        h = self._gather(self.top["embed"], tokens)
-        hs = [h]
-        for lp in self.layers:
-            hs.append(self._fwd(lp, hs[-1]))
+        hs = self._forward(tokens)
         total = 0.0
         norms = {}
         for idx, g in self._grads(tokens, labels, hs):
@@ -422,23 +440,71 @@ class Trainer:
         self.t += 1
         lr = warmup_cosine(self.h, self.t - 1)
         for idx, g in self._grads(tokens, labels, hs):
-            if idx == 0:
-                top = self.top
-                p, m, v = self._adam(top, g, self.m[0], self.v[0],
-                                     scale, lr, float(self.t))
-                self.top, self.m[0], self.v[0] = p, m, v
-            else:
-                p, m, v = self._adam(self.layers[idx - 1], g, self.m[idx],
-                                     self.v[idx], scale, lr, float(self.t))
-                self.layers[idx - 1], self.m[idx], self.v[idx] = p, m, v
+            self._apply(idx, g, scale, lr)
         del hs
         return loss, {k: v * scale for k, v in norms.items()}
 
+    def _forward(self, tokens):
+        hs = [self._gather(self.top["embed"], tokens)]
+        for lp in self.layers:
+            hs.append(self._fwd(lp, hs[-1]))
+        return hs
+
+    def _apply(self, idx, g, scale, lr):
+        """AdamW on the top-level tree (idx 0) or layer idx - 1."""
+        if idx == 0:
+            p, m, v = self._adam(self.top, g, self.m[0], self.v[0],
+                                 scale, lr, float(self.t))
+            self.top, self.m[0], self.v[0] = p, m, v
+        else:
+            p, m, v = self._adam(self.layers[idx - 1], g, self.m[idx],
+                                 self.v[idx], scale, lr, float(self.t))
+            self.layers[idx - 1], self.m[idx], self.v[idx] = p, m, v
+
+    def _local_step(self, tokens, labels):
+        """A step with the exchange between data shards left out: the rows
+        split into `local_groups` shards; each shard's gradient (of the mean
+        loss over its own rows, clipped by its own norm) updates only its
+        own part of each weight, slice k of the leading dimension where
+        that divides evenly and shard 0's whole leaf elsewhere, as each
+        owner of a weight shard would without the reduce-scatter.  The loss
+        is shard 0's."""
+        G = self.local_groups
+        b = tokens.shape[0] // G
+        shards, scales = [], []
+        for k in range(G):
+            tok = self._rows(tokens[k * b:(k + 1) * b])
+            lab = self._rows(labels[k * b:(k + 1) * b])
+            hs = self._forward(tok)
+            total = 0.0
+            for _, g in self._grads(tok, lab, hs):
+                total = total + self._sq(g)
+            if k == 0:
+                loss = float(self._loss)
+            scales.append(min(1.0, 1.0 / (float(jnp.sqrt(total)) + 1e-9)))
+            shards.append((tok, lab, hs))
+
+        def stitch(*gs):
+            n = gs[0].shape[0] if gs[0].ndim >= 2 else 0
+            if not n or n % G:
+                return gs[0] * scales[0]
+            w = n // G
+            return jnp.concatenate([g[k * w:(k + 1) * w] * scales[k]
+                                    for k, g in enumerate(gs)])
+
+        self.t += 1
+        lr = warmup_cosine(self.h, self.t - 1)
+        norms = {}
+        for parts in zip(*(self._grads(*sh) for sh in shards)):
+            idx = parts[0][0]
+            g = jax.tree_util.tree_map(stitch, *(g for _, g in parts))
+            norms.update(flat_norms(g, idx))
+            self._apply(idx, g, 1.0, lr)
+        return loss, norms
+
     def change_norms(self, seed) -> Dict[str, float]:
         """Per-leaf norm of (weights now - weights at the seed)."""
-        w0 = make_weights(self.c, seed, self.pdt)
-        top0, layers0 = split_layers(self.c, w0)
-        del w0
+        top0, layers0 = self._seed_weights(seed)
         out = {}
         diff = jax.jit(lambda a, b: jax.tree_util.tree_map(
             lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b))
@@ -471,11 +537,11 @@ def flat_norms(tree, idx: int) -> Dict[str, float]:
 
 
 def train_reference(c, seed, batches, hyper, mode="f32", mesh=None,
-                    rows_used=1.0, steps=3):
+                    rows_used=1.0, steps=3, local_groups=1):
     """Losses of the first `steps` updates, per-leaf norms of the first
     gradient as the optimizer got it, and per-leaf norms of the change of
     the weights after those steps."""
-    tr = Trainer(c, seed, hyper, mode, mesh, rows_used)
+    tr = Trainer(c, seed, hyper, mode, mesh, rows_used, local_groups)
     losses, g1 = [], None
     for i, (tok, lab) in enumerate(batches[:steps]):
         loss, norms = tr.step(np.asarray(tok), np.asarray(lab))

@@ -37,3 +37,22 @@ def test_training_controls_fail(cpu):
     for mode in ("bf16", "half"):
         c = r["controls"][mode]
         assert any(c[k] > w["limits"][k] for k in w["limits"]), (mode, c)
+
+
+def test_exchange_left_out_fails():
+    """The reference with no gradient exchange between two data shards,
+    against the reference, reads over the tiny cell's limits."""
+    import numpy as np
+    from bench import correct
+    from bench.reference import qwen3 as R
+    w = tiny.train_cell()
+    rng = np.random.default_rng(1)
+    rows = [(t[:, :-1], t[:, 1:]) for t in
+            (rng.integers(0, tiny.CONFIG["vocab_size"], (4, 33))
+             for _ in range(3))]
+    ref = R.train_reference(tiny.CONFIG, SEED, rows, w["optimizer"])
+    local = R.train_reference(tiny.CONFIG, SEED, rows, w["optimizer"],
+                              local_groups=2)
+    nums = correct.train_numbers(local, ref)
+    assert any(nums[k] > w["limits"][k] for k in w["limits"]), nums
+    assert correct.train_numbers(ref, ref)["update_norm_gap"] == 0

@@ -64,3 +64,24 @@ def test_recorded_tpu_trace():
     assert names and all(isinstance(n, str) for n in names)
     assert s["collective_s"] == 0
     assert sum(v for v in s["module_s"].values()) <= s["window_s"]
+
+
+def test_collectives_exposed_per_chip():
+    from bench import readers
+    ops = {"/device:TPU:0": [("while.1", 0, 200), ("fusion.1", 0, 100),
+                             ("async-collective-done.2", 100, 140),
+                             ("fusion.2", 140, 200)],
+           "/device:TPU:1": [("fusion.1", 0, 100),
+                             ("all-gather.4", 90, 120)]}
+    s = T.summarize(T.Trace(ops, {}, [("bench.window", 0, 200)]))
+    per = s["per_device"]
+    # the loop's event holds the collective and does not hide it
+    assert per["/device:TPU:0"]["collective_exposed_s"] == pytest.approx(40e-9)
+    assert per["/device:TPU:1"]["collective_exposed_s"] == pytest.approx(20e-9)
+    assert per["/device:TPU:1"]["busy_s"] == pytest.approx(120e-9)
+    share = readers.collective_exposed_share({"trace": s})
+    assert share == pytest.approx((40 + 20) / 2 / 200)
+    # a trace with no collective has nothing to read
+    one = T.summarize(T.Trace({"/device:TPU:0": [("fusion.1", 0, 100)]}, {},
+                              [("bench.window", 0, 200)]))
+    assert readers.collective_exposed_share({"trace": one}) is None

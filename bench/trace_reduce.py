@@ -18,8 +18,10 @@ import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW = "bench.window"
+# op names of collectives; a TPU runs an async all-gather or reduce-scatter
+# as `async-collective-start` / `async-collective-done` fusions
 COLLECTIVE = re.compile(
-    r"all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all|"
+    r"all-gather|all-reduce|reduce-scatter|collective|all-to-all|"
     r"allgather|allreduce|reducescatter|send|recv", re.I)
 
 Interval = Tuple[float, float]
@@ -126,6 +128,14 @@ def gaps(busy: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
     return subtract([(lo, hi)], busy)
 
 
+def leaves(events):
+    """The events that hold no other: a loop's or a call's event on the
+    ops line spans the ops of its body, which are events of their own."""
+    ev = sorted(events, key=lambda x: (x[1], -x[2]))
+    return [a for a, b in zip(ev, ev[1:] + [None])
+            if b is None or not (b[1] < a[2] and b[2] <= a[2])]
+
+
 def op_times(events, lo: float, hi: float) -> Dict[str, float]:
     t: Dict[str, float] = collections.Counter()
     for n, s, e in events:
@@ -164,7 +174,10 @@ def summarize(trace: Trace, spans: Sequence[Tuple[str, float, float]] = (),
         iv = clip([(s, e) for _, s, e in evs], lo, hi)
         busy = union(iv)
         coll = [(s, e) for n, s, e in evs if COLLECTIVE.search(n)]
-        comp = [(s, e) for n, s, e in evs if not COLLECTIVE.search(n)]
+        # a loop's event spans the collectives in its body: only ops that
+        # hold no other can hide a collective
+        comp = [(s, e) for n, s, e in leaves(evs)
+                if not COLLECTIVE.search(n)]
         exposed = subtract(clip(coll, lo, hi), clip(comp, lo, hi))
         per_dev[dev] = {"busy_s": length(busy) / 1e9,
                         "collective_s":
@@ -188,6 +201,9 @@ def summarize(trace: Trace, spans: Sequence[Tuple[str, float, float]] = (),
         "busy_s": sum(d["busy_s"] for d in per_dev.values()) / len(per_dev),
         "collective_s": per_dev[first]["collective_s"],
         "collective_exposed_s": per_dev[first]["collective_exposed_s"],
+        "per_device": {dev: {k: d[k] for k in
+                             ("busy_s", "collective_s", "collective_exposed_s")}
+                       for dev, d in per_dev.items()},
         "module_s": dict(mods),
         "breakdown": {"device_ops": [[n, t / 1e9] for n, t in top_ops],
                       "idle_gaps": idle},
